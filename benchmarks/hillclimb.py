@@ -11,6 +11,10 @@ Variants:
 
 Usage:
   PYTHONPATH=src python -m benchmarks.hillclimb --exp danube_slot8
+
+A CPU-only tool: it forces 512 host devices at import. Keep it off the chip
+path — on a TPU host it would hold the chip for a run that measures nothing
+there.
 """
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("PREPEND_XLA_FLAGS", "") +

@@ -40,6 +40,8 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
 
     quick = os.environ.get("QUICK", "1") != "0"
+    from repro.utils import use_compile_cache
+    use_compile_cache()
     import benchmarks.bench_grouped_gemm as g
     import benchmarks.bench_attention as a
     import benchmarks.bench_inference_scaling as i

@@ -88,7 +88,10 @@ class ArchConfig:
     max_position: int = 131072
     dtype: str = "bfloat16"
     remat: str = "full"        # none | dots | full
-    attn_impl: str = "dense"   # dense | chunked (flash-style online softmax)
+    # dense | chunked (flash-style online softmax) | pallas (the flash and
+    # decode kernels; interpret mode only with kernel_backend=
+    # 'pallas_interpret')
+    attn_impl: str = "dense"
     # Diagonal-executor grouped-block implementation: 'vmap' applies the
     # scalar block per slot via jax.vmap (exactness oracle, autodiff-safe);
     # 'fused' launches the grouped Pallas kernels over the whole group

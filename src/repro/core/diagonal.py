@@ -440,6 +440,13 @@ def _run_diagonal_banded(layout: StackLayout, params: Dict, state0: Dict,
     carry = (jnp.zeros((L,) + segments.shape[1:], segments.dtype), state0)
     ys_parts, cap_parts = [], []
     for (i0, n, Gb, mode) in _band_phases(S, L):
+        # a band's weight slice is a copy where a kernel consumes it (a
+        # Pallas call takes no fused slice). The slices depend on params
+        # alone, so XLA may schedule them all up front — 2*min(S, L) - 2
+        # partial copies of the weight stack live at once (15 GB at
+        # llama-1b-armt, S=16). Tying params to the carry keeps each
+        # phase's slices inside that phase.
+        params, carry = jax.lax.optimization_barrier((params, carry))
         body = _diag_body(layout, params, apply_block, S,
                           grouped_apply=grouped_apply,
                           capture_states=capture_states, band=(Gb, mode))
@@ -618,6 +625,28 @@ def pipeline_step(layout: StackLayout, params: Dict, xs: jax.Array,
     ``retain_pos`` row lands in ``carry['brow']`` instead of a full ``ys``
     write (DESIGN.md §15).
     """
+    _constrain_states = _constrain_states_fn(buf_spec)
+    carry = dict(carry, state=dict(
+        carry["state"],
+        pattern=_constrain_states(tuple(carry["state"]["pattern"]))))
+    sub = _group_advance(layout, params, apply_block, carry,
+                         buf_spec=buf_spec, grouped_apply=grouped_apply,
+                         remat=remat, retain_pos=retain_pos)
+    # loop on the carry's own cursor: its trip count is not a compile-time
+    # constant, so XLA keeps the loop for every n_groups — a one-iteration
+    # scan would be inlined and compiled with other fusions and layouts,
+    # off run_diagonal's scan by an ulp that the ARMT recurrence amplifies
+    end = carry["step"] + n_groups
+    return jax.lax.while_loop(lambda c: c["step"] < end,
+                              lambda c: sub(xs, c), carry)
+
+
+def _group_advance(layout: StackLayout, params: Dict, apply_block: ApplyBlock,
+                   carry: Dict, *, buf_spec, grouped_apply, remat: bool,
+                   retain_pos: int):
+    """``sub(xs, c) -> c``: one anti-diagonal group of a suspended pipeline
+    shaped like ``carry`` — the shared step body plus the ys/win/cap writes
+    and the cursor advance."""
     stream = "win" in carry
     S = carry["brow"].shape[0] if stream else carry["ys"].shape[0]
     L = layout.n_layers
@@ -627,12 +656,8 @@ def pipeline_step(layout: StackLayout, params: Dict, xs: jax.Array,
                       grouped_apply=grouped_apply, capture_states=capture)
     if remat:
         body = jax.checkpoint(body)
-    _constrain_states = _constrain_states_fn(buf_spec)
-    carry = dict(carry, state=dict(
-        carry["state"],
-        pattern=_constrain_states(tuple(carry["state"]["pattern"]))))
 
-    def sub(c, _):
+    def sub(xs, c):
         i = c["step"]
         seg_in = jax.lax.dynamic_index_in_dim(
             xs, jnp.minimum(i, xs.shape[0] - 1), 0, keepdims=False)
@@ -675,10 +700,9 @@ def pipeline_step(layout: StackLayout, params: Dict, xs: jax.Array,
                     b, jnp.where(sok, e.astype(b.dtype), old), si, 0)
 
             new["cap"] = jax.tree_util.tree_map(wr, c["cap"], cap_e)
-        return new, None
+        return new
 
-    carry, _ = jax.lax.scan(sub, carry, None, length=n_groups)
-    return carry
+    return sub
 
 
 def pipeline_step_pool(layout: StackLayout, params: Dict, xs_pool: jax.Array,
@@ -691,10 +715,10 @@ def pipeline_step_pool(layout: StackLayout, params: Dict, xs_pool: jax.Array,
 
     ``xs_pool`` / ``carry_pool`` are ``pipeline_step``'s arguments with a
     leading pool axis [N, ...] — N same-shape (S, B, T, D) carries stacked
-    leaf-wise, including N independent ``step`` cursors [N]. The pool rides
-    one ``jax.vmap`` of the single-carry step, so each member runs the
-    exact same math as its own ``pipeline_step`` call — bit-identical by
-    construction, which is the pooled==blocking token-identity argument.
+    leaf-wise, including N independent ``step`` cursors [N]. Each group of
+    the pool is one ``jax.vmap`` of the single-carry group advance, so each
+    member runs the exact same math as its own ``pipeline_step`` call —
+    the pooled==blocking token-identity argument.
     Heterogeneous progress is safe for the same reason fixed-budget
     stepping is: a member whose cursor overshot its grid (or a pow2 pad
     entry parked at the end, ``pipeline_pool_pad``) executes masked no-ops.
@@ -715,13 +739,19 @@ def pipeline_step_pool(layout: StackLayout, params: Dict, xs_pool: jax.Array,
             lambda a, s: jax.lax.with_sharding_constraint(a, s),
             tree, pool_spec)
 
-    def step_one(xs, carry):
-        return pipeline_step(layout, params, xs, carry, apply_block,
-                             n_groups=n_groups, buf_spec=None,
-                             grouped_apply=grouped_apply, remat=remat,
-                             retain_pos=retain_pos)
-
-    return constrain(jax.vmap(step_one)(xs_pool, constrain(carry_pool)))
+    carry_pool = constrain(carry_pool)
+    one = jax.tree_util.tree_map(lambda a: a[0], carry_pool)
+    sub = jax.vmap(_group_advance(layout, params, apply_block, one,
+                                  buf_spec=None, grouped_apply=grouped_apply,
+                                  remat=remat, retain_pos=retain_pos))
+    # one loop for the whole pool, on a counter that starts at a run-time
+    # value (so XLA keeps the loop, as in pipeline_step) and is not batched
+    # (so no per-member select of the carry on every iteration)
+    j0 = jnp.min(carry_pool["step"])
+    _, carry_pool = jax.lax.while_loop(
+        lambda jc: jc[0] < j0 + n_groups,
+        lambda jc: (jc[0] + 1, sub(xs_pool, jc[1])), (j0, carry_pool))
+    return constrain(carry_pool)
 
 
 def pipeline_pool_pad(xs: jax.Array, carry: Dict, n_steps: int):

@@ -7,9 +7,15 @@ nu=3 in the paper -> 6*d_mem). Once per segment:
   read (eq 6):    AssociativeLayer(x) = A phi(W_Q x) / (z^T phi(W_Q x))
   update (3-5):   k,v = W_K m, W_V m;  beta = sigmoid(W_beta m)
                   vbar  = A phi(k) / (z^T phi(k))
-                  gamma = 1 - z^T phi(k) / ||phi(k)||^2
+                  gamma = clip(1 - z^T phi(k) / ||phi(k)||^2, 0, 1)
                   A <- A + sum_i beta_i (v_i - vbar_i) (x) phi(k_i)
                   z <- z + sum_i gamma_i phi(k_i)
+
+gamma is clipped to [0, 1], which eq. 5 as printed does not do: unclipped,
+gamma goes negative, z follows, and the denominators z^T phi(.) + eps pass
+through zero — with seeded random weights at llama-1b-armt's widths the
+hidden state is non-finite within 16 segments (DESIGN.md §7). With the clip
+z stays non-negative (phi is), so every denominator is at least eps.
 
 The read is applied residually to every position of the segment input; the
 update uses the transformer-layer *outputs* at the memory-token positions.
@@ -116,7 +122,7 @@ def mem_update(params: Dict[str, jax.Array], state: Dict[str, jax.Array],
     pk = dpfp(k, acfg.nu)                                        # [B,M,P]
     zk = jnp.einsum("bmp,bp->bm", pk, state["z"])                # z^T phi(k)
     vbar = jnp.einsum("bmp,bpv->bmv", pk, state["A"]) / (zk + EPS)[..., None]
-    gamma = 1.0 - zk / (jnp.sum(pk * pk, axis=-1) + EPS)
+    gamma = jnp.clip(1.0 - zk / (jnp.sum(pk * pk, axis=-1) + EPS), 0.0, 1.0)
     A_new = state["A"] + jnp.einsum("bm,bmv,bmp->bpv", beta, v - vbar, pk)
     z_new = state["z"] + jnp.einsum("bm,bmp->bp", gamma, pk)
     return {"A": A_new, "z": z_new}

@@ -10,7 +10,9 @@ The paper-specific hot spot (eqs. 3-6). Two kernels:
                (memory tokens M is small — one block).
 
 Layout: x [N, T, D], A [N, P, Dv], z [N, P] with N = group*batch (the diagonal
-executor's grouped launch), P = 2*nu*d_mem.
+executor's grouped launch), P = 2*nu*d_mem. Inside the kernels z is the
+[1, P] row of an [N, 1, P] view, so every block's last two dims are a legal
+TPU tile.
 
 Projection weights may be shared across N (``wq: [D, dm]``) or stacked per
 group (``wq: [G, D, dm]`` with N = G*batch) — the grouped-block fast path
@@ -52,13 +54,22 @@ def _dpfp(x, nu: int):
         [r * jnp.roll(r, j, axis=-1) for j in range(1, nu + 1)], axis=-1)
 
 
+def _dot_nt(a, b):
+    """``a @ b.T`` for a [m, k] and b [n, k], fp32 (the row-vector form the
+    normalizer ``z`` takes in VMEM)."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())))
+
+
 def _read_kernel(x_ref, wq_ref, a_ref, z_ref, o_ref, *, nu: int):
-    # x: [bt, D], wq: [D, dm], a: [P, bv], z: [P], o: [bt, bv]
+    # x: [bt, D], wq: [D, dm], a: [P, bv], z: [1, P], o: [bt, bv]
     x = x_ref[...].astype(jnp.float32)
     q = x @ wq_ref[...].astype(jnp.float32)
     pq = _dpfp(q, nu)                                       # [bt, P]
     num = pq @ a_ref[...].astype(jnp.float32)               # [bt, bv]
-    den = pq @ z_ref[...].astype(jnp.float32)[:, None]      # [bt, 1]
+    # a lane reduction, not a matmul: Mosaic folds `dot + EPS` into a
+    # non-zero matmul accumulator, which it refuses
+    den = jnp.sum(pq * z_ref[...].astype(jnp.float32), axis=-1,
+                  keepdims=True)                            # [bt, 1]
     o_ref[...] = (num / (den + EPS)).astype(o_ref.dtype)
 
 
@@ -72,6 +83,8 @@ def armt_read(x, wq, A, z, *, nu: int = 3, block_t: int = 256,
     block_t = min(block_t, T)
     block_v = min(block_v, Dv)
     grid = (N, pl.cdiv(T, block_t), pl.cdiv(Dv, block_v))
+    # z rides as [N, 1, P]: a (1, P) block is a legal TPU tile, where a
+    # (None, P) block of [N, P] squeezes the sublane dim and is refused
     return pl.pallas_call(
         functools.partial(_read_kernel, nu=nu),
         grid=grid,
@@ -79,19 +92,19 @@ def armt_read(x, wq, A, z, *, nu: int = 3, block_t: int = 256,
             pl.BlockSpec((None, block_t, D), lambda n, it, iv: (n, it, 0)),
             _wspec(wq, N),
             pl.BlockSpec((None, P, block_v), lambda n, it, iv: (n, 0, iv)),
-            pl.BlockSpec((None, P), lambda n, it, iv: (n, 0)),
+            pl.BlockSpec((None, 1, P), lambda n, it, iv: (n, 0, 0)),
         ],
         out_specs=pl.BlockSpec((None, block_t, block_v),
                                lambda n, it, iv: (n, it, iv)),
         out_shape=jax.ShapeDtypeStruct((N, T, Dv), x.dtype),
         interpret=interpret,
-    )(x, wq, A, z)
+    )(x, wq, A, z[:, None, :])
 
 
 def _update_kernel(m_ref, wk_ref, wv_ref, wb_ref, a_ref, z_ref,
                    a_out_ref, z_out_ref, *, nu: int):
     # m: [M, D]; wk: [D, dm]; wv: [D, bv]; wb: [D, 1];
-    # a: [P, bv]; z: [P]  ->  a_out: [P, bv]; z_out: [P]
+    # a: [P, bv]; z: [1, P]  ->  a_out: [P, bv]; z_out: [1, P]
     m = m_ref[...].astype(jnp.float32)
     k = m @ wk_ref[...].astype(jnp.float32)
     pk = _dpfp(k, nu)                                        # [M, P]
@@ -99,14 +112,15 @@ def _update_kernel(m_ref, wk_ref, wv_ref, wb_ref, a_ref, z_ref,
     beta = jax.nn.sigmoid(m @ wb_ref[...].astype(jnp.float32))  # [M, 1]
     a = a_ref[...].astype(jnp.float32)
     z = z_ref[...].astype(jnp.float32)
-    zk = pk @ z[:, None]                                     # [M, 1]
+    zk = _dot_nt(pk, z)                                      # [M, 1]
     vbar = (pk @ a) / (zk + EPS)
     a_out_ref[...] = (a + pk.T @ (beta * (v - vbar))).astype(a_out_ref.dtype)
 
     @pl.when(pl.program_id(1) == 0)
     def _z():
-        gamma = 1.0 - zk[:, 0] / (jnp.sum(pk * pk, axis=-1) + EPS)   # [M]
-        z_out_ref[...] = (z + (gamma[None, :] @ pk)[0]).astype(z_out_ref.dtype)
+        gamma = jnp.clip(1.0 - zk[:, 0] / (jnp.sum(pk * pk, axis=-1) + EPS),
+                         0.0, 1.0)                                  # [M]
+        z_out_ref[...] = (z + gamma[None, :] @ pk).astype(z_out_ref.dtype)
 
 
 @functools.partial(
@@ -118,7 +132,7 @@ def armt_update(m, wk, wv, wb, A, z, *, nu: int = 3, block_v: int = 512,
     _, P, Dv = A.shape
     block_v = min(block_v, Dv)
     grid = (N, pl.cdiv(Dv, block_v))
-    return pl.pallas_call(
+    A2, z2 = pl.pallas_call(
         functools.partial(_update_kernel, nu=nu),
         grid=grid,
         in_specs=[
@@ -127,15 +141,16 @@ def armt_update(m, wk, wv, wb, A, z, *, nu: int = 3, block_v: int = 512,
             _wspec(wv, N, last_block=block_v, last_axis=0),
             _wspec(wb, N),
             pl.BlockSpec((None, P, block_v), lambda n, iv: (n, 0, iv)),
-            pl.BlockSpec((None, P), lambda n, iv: (n, 0)),
+            pl.BlockSpec((None, 1, P), lambda n, iv: (n, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((None, P, block_v), lambda n, iv: (n, 0, iv)),
-            pl.BlockSpec((None, P), lambda n, iv: (n, 0)),
+            pl.BlockSpec((None, 1, P), lambda n, iv: (n, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(A.shape, A.dtype),
-            jax.ShapeDtypeStruct(z.shape, z.dtype),
+            jax.ShapeDtypeStruct((N, 1, P), z.dtype),
         ],
         interpret=interpret,
-    )(m, wk, wv, wb, A, z)
+    )(m, wk, wv, wb, A, z[:, None, :])
+    return A2, z2[:, 0, :]

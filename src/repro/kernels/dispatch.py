@@ -5,7 +5,7 @@ kernel vs. XLA-native jnp oracle) and *with which tuning config* (block
 sizes, interpret-mode lowering, epilogue fusion) — so ``forward_hidden``,
 the fused grouped-block path, and the serving scheduler's pooled launches
 all make the same decision through one table instead of scattered
-``on_tpu()`` checks.
+backend checks.
 
 Resolution order (``resolve``):
 
@@ -26,7 +26,8 @@ so it must stay pure-static: a dict lookup, no timing, no device work.
 Sweeps happen strictly offline in autotune.py. Each resolution increments
 ``kernel_dispatch_total{op,impl,backend,source}`` in the serve-stack
 metrics registry — once per compiled specialization, which is exactly the
-cardinality a dispatch counter should have.
+cardinality a dispatch counter should have. ``impl`` is ``xla``,
+``pallas`` (compiled Mosaic kernel) or ``pallas_interpret``.
 
 Shape bucketing: every dim is rounded up to a power of two, so one tuned
 config serves e.g. all of M in (65..128] — the diagonal executor's grouped
@@ -115,9 +116,14 @@ PALLAS_INTERPRET = KernelConfig(impl="pallas", interpret=True)
 
 
 def backend() -> str:
-    """The active JAX backend as a heuristic-table key."""
+    """The active JAX backend as a heuristic-table key. A backend the table
+    does not know is an error: mapping it to another key would dispatch
+    lowerings chosen for a different device."""
     b = jax.default_backend()
-    return b if b in ("cpu", "gpu", "tpu") else "cpu"
+    if b not in ("cpu", "gpu", "tpu"):
+        raise RuntimeError(f"kernel dispatch has no table for JAX backend "
+                           f"{b!r} (known: cpu, gpu, tpu)")
+    return b
 
 
 # ---------------------------------------------------------------------------
@@ -180,10 +186,12 @@ _cache_path: Optional[str] = None
 
 
 def default_cache_path() -> str:
-    return os.environ.get(
-        "REPRO_KERNEL_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                     "kernel_cache.json"))
+    """``REPRO_KERNEL_CACHE`` if set, else ``.kernel_cache.json`` at the
+    checkout root (gitignored) — never a file outside the checkout, so a
+    dispatch decision depends only on what the checkout holds."""
+    from repro.utils import CHECKOUT_ROOT
+    return os.environ.get("REPRO_KERNEL_CACHE",
+                          str(CHECKOUT_ROOT / ".kernel_cache.json"))
 
 
 def set_cache_path(path: Optional[str]) -> None:
@@ -200,13 +208,17 @@ def _load_cache() -> Dict[str, KernelConfig]:
         path = _cache_path or default_cache_path()
         _cache = {}
         if os.path.exists(path):
-            try:
-                with open(path) as f:
+            # a file that does not parse is an error, not an empty cache:
+            # silently dropping it would change every dispatch decision
+            with open(path) as f:
+                try:
                     raw = json.load(f)
-                _cache = {k: KernelConfig.from_json(v)
-                          for k, v in raw.get("configs", {}).items()}
-            except (OSError, ValueError, TypeError):
-                _cache = {}
+                    _cache = {k: KernelConfig.from_json(v)
+                              for k, v in raw["configs"].items()}
+                except (ValueError, TypeError, KeyError, AttributeError) as e:
+                    raise ValueError(
+                        f"corrupt kernel autotune cache {path}: {e!r}; "
+                        "delete it or re-run the autotuner") from e
     return _cache
 
 
@@ -288,6 +300,8 @@ def resolve(op: str, shapes: Iterable[Tuple[int, ...]], dtype, *,
             cfg, source = hit, "cache"
         else:
             cfg, source = heuristic(op, bk), "heuristic"
-    _registry().inc("kernel_dispatch_total", op=op, impl=cfg.impl,
+    impl = ("pallas_interpret" if cfg.impl == "pallas" and cfg.interpret
+            else cfg.impl)
+    _registry().inc("kernel_dispatch_total", op=op, impl=impl,
                     backend=backend(), source=source)
     return cfg

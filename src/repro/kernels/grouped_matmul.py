@@ -22,6 +22,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 ACTIVATIONS = (None, "silu", "gelu")
 
+# The fused ARMT epilogue holds one layer's whole wv [D, Dv] and A [P, Dv]
+# in VMEM next to the GEMM tiles: ~37 MB double-buffered at llama-1b-armt
+# widths, past the 16 MB default scoped limit. A TPU v5e core has 128 MiB.
+_FUSED_VMEM_LIMIT = 100 * 2**20
+
 
 def _epilogue(acc, activation: str | None):
     if activation == "silu":
@@ -62,14 +67,14 @@ def _gmm_bias_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, n_k: int,
 
     @pl.when(ik == n_k - 1)
     def _done():
-        acc = acc_ref[...] + b_ref[...].astype(jnp.float32)[None, :]
+        acc = acc_ref[...] + b_ref[...].astype(jnp.float32)    # [1, bn]
         o_ref[...] = _epilogue(acc, activation).astype(o_ref.dtype)
 
 
 def _gmm_armt_kernel(x_ref, w_ref, res_ref, b_ref, wk_ref, wv_ref, wb_ref,
                      a_ref, z_ref, y_ref, a_out_ref, z_out_ref, acc_ref, *,
                      n_m: int, n_k: int, mem_off: int, M: int, nu: int):
-    from repro.kernels.armt_memory import EPS, _dpfp
+    from repro.kernels.armt_memory import EPS, _dot_nt, _dpfp
     im, ik = pl.program_id(1), pl.program_id(2)
 
     @pl.when(ik == 0)
@@ -82,7 +87,7 @@ def _gmm_armt_kernel(x_ref, w_ref, res_ref, b_ref, wk_ref, wv_ref, wb_ref,
 
     @pl.when(ik == n_k - 1)
     def _done():
-        out = (acc_ref[...] + b_ref[...].astype(jnp.float32)[None, :]
+        out = (acc_ref[...] + b_ref[...].astype(jnp.float32)   # [1, D]
                + res_ref[...].astype(jnp.float32))
         y = out.astype(y_ref.dtype)
         y_ref[...] = y
@@ -99,14 +104,15 @@ def _gmm_armt_kernel(x_ref, w_ref, res_ref, b_ref, wk_ref, wv_ref, wb_ref,
             v = m @ wv_ref[...].astype(jnp.float32)              # [M, Dv]
             beta = jax.nn.sigmoid(m @ wb_ref[...].astype(jnp.float32))
             a = a_ref[...].astype(jnp.float32)
-            z = z_ref[...].astype(jnp.float32)
-            zk = pk @ z[:, None]                                 # [M, 1]
+            z = z_ref[...].astype(jnp.float32)                   # [1, P]
+            zk = _dot_nt(pk, z)                                  # [M, 1]
             vbar = (pk @ a) / (zk + EPS)
             a_out_ref[...] = (
                 a + pk.T @ (beta * (v - vbar))).astype(a_out_ref.dtype)
-            gamma = 1.0 - zk[:, 0] / (jnp.sum(pk * pk, axis=-1) + EPS)
+            gamma = jnp.clip(
+                1.0 - zk[:, 0] / (jnp.sum(pk * pk, axis=-1) + EPS), 0.0, 1.0)
             z_out_ref[...] = (
-                z + (gamma[None, :] @ pk)[0]).astype(z_out_ref.dtype)
+                z + gamma[None, :] @ pk).astype(z_out_ref.dtype)
 
 
 @functools.partial(
@@ -169,27 +175,30 @@ def grouped_matmul_armt_update(x, w, res, wk, wv, wb, A, z, bias=None, *,
                          lambda g, im, ik: (g, ik, 0)),
             pl.BlockSpec((None, block_m, D),
                          lambda g, im, ik: (g, im, 0)),
-            pl.BlockSpec((None, D), lambda g, im, ik: (g, 0)),
+            pl.BlockSpec((None, 1, D), lambda g, im, ik: (g, 0, 0)),
             _wspec(wk, G),
             _wspec(wv, G),
             _wspec(wb, G),
             pl.BlockSpec((None, P, Dv), lambda g, im, ik: (g, 0, 0)),
-            pl.BlockSpec((None, P), lambda g, im, ik: (g, 0)),
+            pl.BlockSpec((None, 1, P), lambda g, im, ik: (g, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((None, block_m, D), lambda g, im, ik: (g, im, 0)),
             pl.BlockSpec((None, P, Dv), lambda g, im, ik: (g, 0, 0)),
-            pl.BlockSpec((None, P), lambda g, im, ik: (g, 0)),
+            pl.BlockSpec((None, 1, P), lambda g, im, ik: (g, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((G, Rp, D), x.dtype),
             jax.ShapeDtypeStruct(A.shape, A.dtype),
-            jax.ShapeDtypeStruct(z.shape, z.dtype),
+            jax.ShapeDtypeStruct((G, 1, P), z.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((block_m, D), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_FUSED_VMEM_LIMIT),
         interpret=interpret,
-    )(x, w, res, bias, wk, wv, wb, A, z)
-    return (y[:, :R, :] if Rp != R else y), A2, z2
+        # bias and z ride as [G, 1, N] rows (legal (1, N) TPU tiles)
+    )(x, w, res, bias[:, None, :], wk, wv, wb, A, z[:, None, :])
+    return (y[:, :R, :] if Rp != R else y), A2, z2[:, 0, :]
 
 
 @functools.partial(
@@ -236,11 +245,13 @@ def grouped_matmul(x, w, bias=None, *, activation: str | None = None,
         operands = (x, w)
     else:
         assert bias.shape == (G, Np), (bias.shape, (G, Np))
-        in_specs.append(pl.BlockSpec((None, block_n),
-                                     lambda g, im, jn, ik: (g, jn)))
+        # [G, 1, N] with a (1, block_n) block: a legal TPU tile, where a
+        # (None, block_n) block of [G, N] squeezes the sublane dim
+        in_specs.append(pl.BlockSpec((None, 1, block_n),
+                                     lambda g, im, jn, ik: (g, 0, jn)))
         kernel = functools.partial(_gmm_bias_kernel, n_k=n_k,
                                    activation=activation)
-        operands = (x, w, bias)
+        operands = (x, w, bias[:, None, :])
     out = pl.pallas_call(
         kernel,
         grid=grid,

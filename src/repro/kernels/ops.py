@@ -6,7 +6,6 @@ in that order. MXU-alignment padding stays here (the resolver is
 shape-bucketed; padding is an op-local concern)."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels import dispatch, ref
@@ -19,10 +18,6 @@ from repro.kernels.decode_attention import decode_attention as \
     decode_attention_kernel
 from repro.kernels.mamba_scan import mamba_scan
 from repro.utils import round_up
-
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _pad_axis(x, axis: int, to: int):
@@ -154,6 +149,9 @@ def grouped_gemm_armt_update(x, w, res, wk, wv, wb, A, z, bias=None, *,
     if cfg.impl == "xla":
         return ref.grouped_matmul_armt_update_ref(x, w, res, wk, wv, wb,
                                                   A, z, bias, M=M, nu=nu)
+    # trace-time count of which form ran, like kernel_dispatch_total
+    dispatch._registry().inc("armt_update_epilogue_total",
+                             form="fused" if fusable else "two_launch")
     if not fusable:
         y = res + grouped_matmul(x, w, bias, interpret=cfg.interpret,
                                  **cfg.blocks("block_m", "block_k"))

@@ -204,7 +204,7 @@ def armt_update_ref(m, wk, wv, wb, A, z, *, nu: int = 3):
     zk = jnp.einsum("nmp,np->nm", pk, z.astype(jnp.float32))
     vbar = jnp.einsum("nmp,npv->nmv", pk, A.astype(jnp.float32)) \
         / (zk + EPS)[..., None]
-    gamma = 1.0 - zk / (jnp.sum(pk * pk, axis=-1) + EPS)
+    gamma = jnp.clip(1.0 - zk / (jnp.sum(pk * pk, axis=-1) + EPS), 0.0, 1.0)
     A_new = A.astype(jnp.float32) + jnp.einsum("nm,nmv,nmp->npv",
                                                beta, v - vbar, pk)
     z_new = z.astype(jnp.float32) + jnp.einsum("nm,nmp->np", gamma, pk)

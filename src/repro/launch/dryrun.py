@@ -2,6 +2,9 @@
 production mesh and record memory/cost/roofline artifacts.
 
 MUST set the host-device override before any other import touches jax.
+This is a CPU-only tool: it forces 512 host devices at import and compiles
+for them. Keep it off the chip path — on a TPU host it would hold the chip
+for a run that measures nothing there.
 """
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("PREPEND_XLA_FLAGS", "") +

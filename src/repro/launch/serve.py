@@ -129,6 +129,8 @@ def main():
     from repro.models import init_params
     from repro.serve import (PrefixCache, Request, RequestError, ServeEngine,
                              SessionStore, Telemetry, validate_chrome_trace)
+    from repro.utils import use_compile_cache
+    use_compile_cache()
 
     cfg = (get_smoke_config(args.arch) if args.smoke
            else get_config(args.arch))

@@ -172,12 +172,14 @@ def attention(x, p, cfg, *, positions=None, mask=None, bidirectional=False):
                          window=cfg.sliding_window)
     elif impl == "pallas":
         # the TPU flash kernel (kernels/flash_attention.py); interpret mode
-        # executes the kernel body on CPU for validation
+        # (the kernel body on CPU, for validation) only where the caller
+        # asked for it with kernel_backend='pallas_interpret'
         from repro.kernels import ops as kops
         o = kops.segment_attention(
             q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
             causal=not bidirectional, window=cfg.sliding_window,
-            use_kernel=True, interpret=not kops.on_tpu()).swapaxes(1, 2)
+            use_kernel=True,
+            interpret=cfg.kernel_backend == "pallas_interpret").swapaxes(1, 2)
     else:
         if mask is None and not bidirectional:
             mask = causal_mask(T, T, window=cfg.sliding_window)
@@ -254,10 +256,10 @@ def decode_attention(x, p, cfg, cache: Dict, pos: jax.Array):
         # (kernels/decode_attention.py) reads only the valid cache prefix
         from repro.kernels import ops as kops
         lens = (pos if per_slot else jnp.full((B,), pos, jnp.int32)) + 1
-        o = kops.decode_attention(q[:, 0], ck, cv, lens,
-                                  window=cfg.sliding_window,
-                                  use_kernel=True,
-                                  interpret=not kops.on_tpu())[:, None]
+        o = kops.decode_attention(
+            q[:, 0], ck, cv, lens, window=cfg.sliding_window,
+            use_kernel=True,
+            interpret=cfg.kernel_backend == "pallas_interpret")[:, None]
     else:
         o = sdpa(q, ck, cv, mask)
     o = o.reshape(B, Tq, cfg.n_heads * cfg.head_dim)

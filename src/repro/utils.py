@@ -3,12 +3,33 @@ from __future__ import annotations
 
 import functools
 import math
+import os
 import time
+from pathlib import Path
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+# the checkout that holds src/repro: per-checkout caches live under it
+CHECKOUT_ROOT = Path(__file__).resolve().parents[2]
+
+
+def use_compile_cache() -> str:
+    """Give JAX's persistent compilation cache a fixed directory and return
+    it. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here; otherwise the cache goes to ``.jax_cache`` at the
+    checkout root (gitignored) — a fixed path, since the directory is part
+    of what a later run must find again. Every entry point calls this
+    before its first compile."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(CHECKOUT_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def round_up(x: int, m: int) -> int:

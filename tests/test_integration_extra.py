@@ -20,7 +20,8 @@ def test_model_pallas_attention_matches_dense():
     params = init_params(cfg, jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(1), (1, 32), 8, cfg.vocab)
     h1, _ = forward_hidden(params, cfg, toks, seg_len=16)
-    cfgp = dataclasses.replace(cfg, attn_impl="pallas")
+    cfgp = dataclasses.replace(cfg, attn_impl="pallas",
+                               kernel_backend="pallas_interpret")
     h2, _ = forward_hidden(params, cfgp, toks, seg_len=16)
     np.testing.assert_allclose(np.asarray(h1), np.asarray(h2),
                                atol=5e-4, rtol=5e-4)

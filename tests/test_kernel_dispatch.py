@@ -131,6 +131,58 @@ def test_dispatch_counters_in_registry(cache):
     assert reg.counters.get(key) == 1
 
 
+def test_interpret_dispatch_is_counted_as_interpret(cache):
+    reg = default_registry()
+    reg.remove_series("kernel_dispatch_total")
+    dispatch.resolve("armt_read", ((2, 8, 12), (2, 24, 16)), jnp.float32,
+                     use_kernel=True, interpret=True)
+    key = ("kernel_dispatch_total{backend=cpu,impl=pallas_interpret,"
+           "op=armt_read,source=override}")
+    assert reg.counters.get(key) == 1
+
+
+def test_unknown_backend_is_an_error(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "metal")
+    with pytest.raises(RuntimeError, match="metal"):
+        dispatch.backend()
+
+
+def test_corrupt_cache_is_an_error(cache):
+    with open(cache, "w") as f:
+        f.write("{not json")
+    with pytest.raises(ValueError, match="corrupt kernel autotune cache"):
+        dispatch.resolve("armt_read", ((2, 8, 12), (2, 24, 16)), jnp.float32)
+
+
+def test_default_cache_path_is_inside_the_checkout(monkeypatch):
+    from repro.utils import CHECKOUT_ROOT
+    monkeypatch.delenv("REPRO_KERNEL_CACHE", raising=False)
+    assert dispatch.default_cache_path() == str(
+        CHECKOUT_ROOT / ".kernel_cache.json")
+
+
+@pytest.mark.parametrize("env", [None, "/elsewhere/jax-cache"])
+def test_compile_cache_dir(monkeypatch, env):
+    """The entry points' compile cache: JAX_COMPILATION_CACHE_DIR is left to
+    JAX untouched; unset, the cache goes to a fixed path in the checkout."""
+    from repro.utils import CHECKOUT_ROOT, use_compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    try:
+        got = use_compile_cache()
+        if env is None:
+            assert got == str(CHECKOUT_ROOT / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            assert got == env
+            assert jax.config.jax_compilation_cache_dir == was
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
 def test_heuristic_table_covers_every_op_and_backend():
     for bk in dispatch.BACKENDS:
         for op in dispatch.OPS:
