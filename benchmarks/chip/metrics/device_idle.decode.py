@@ -1,0 +1,8 @@
+"""Device idle share of the traced window: one minus the union of the
+device operations' intervals over the window's length."""
+
+
+def read(run):
+    if run.trace is None or run.trace.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - run.trace.busy_s() / run.trace.window_s)
