@@ -97,6 +97,7 @@ def armt_read(x, wq, A, z, *, nu: int = 3, block_t: int = 256,
         out_specs=pl.BlockSpec((None, block_t, block_v),
                                lambda n, it, iv: (n, it, iv)),
         out_shape=jax.ShapeDtypeStruct((N, T, Dv), x.dtype),
+        name="armt_read",
         interpret=interpret,
     )(x, wq, A, z[:, None, :])
 
@@ -151,6 +152,7 @@ def armt_update(m, wk, wv, wb, A, z, *, nu: int = 3, block_v: int = 512,
             jax.ShapeDtypeStruct(A.shape, A.dtype),
             jax.ShapeDtypeStruct((N, 1, P), z.dtype),
         ],
+        name="armt_update",
         interpret=interpret,
     )(m, wk, wv, wb, A, z[:, None, :])
     return A2, z2[:, 0, :]

@@ -122,6 +122,7 @@ def decode_attention(q, k, v, lengths, *, window: int = 0,
                             pltpu.VMEM((rep, 1), jnp.float32),
                             pltpu.VMEM((rep, hd), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rep, hd), q.dtype),
+        name="decode_attention",
         interpret=interpret,
     )(lengths, q, k, v)
     return out.reshape(B, Hq, hd)

@@ -133,6 +133,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         out_specs=pl.BlockSpec((None, None, block_q, hd),
                                lambda n, h, iq: (n, h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)
     return out[:, :, :T, :] if T_pad != T else out

@@ -195,6 +195,7 @@ def grouped_matmul_armt_update(x, w, res, wk, wv, wb, A, z, bias=None, *,
         scratch_shapes=[pltpu.VMEM((block_m, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_FUSED_VMEM_LIMIT),
+        name="grouped_matmul_armt_update",
         interpret=interpret,
         # bias and z ride as [G, 1, N] rows (legal (1, N) TPU tiles)
     )(x, w, res, bias[:, None, :], wk, wv, wb, A, z[:, None, :])
@@ -260,6 +261,7 @@ def grouped_matmul(x, w, bias=None, *, activation: str | None = None,
                                lambda g, im, jn, ik: (g, im, jn)),
         out_shape=jax.ShapeDtypeStruct((G, Mp, Np), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
+        name="grouped_matmul",
         interpret=interpret,
     )(*operands)
     return out[:, :M, :N] if (Mp, Np) != (M, N) else out

@@ -70,6 +70,7 @@ def mamba_scan(x, dt, Bt, Ct, A_log, D, h0, *, block_i: int = 512,
             jax.ShapeDtypeStruct((B, dI, dS), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_i, dS), jnp.float32)],
+        name="mamba_scan",
         interpret=interpret,
     )(x, dt, Bt, Ct, A_log, D, h0)
     return y, hT

@@ -23,14 +23,21 @@ the collectives. Sharded serving is token-identical to single-device
 (tests/test_serve_sharded.py).
 
 Observability (DESIGN.md §13): ``--trace-out trace.json`` records the
-chunk-granular span timeline (Chrome-trace JSON for
-https://ui.perfetto.dev; schema-validated on export, gated in CI via
-``python -m repro.serve.telemetry``), ``--metrics`` / ``--metrics-out``
-dump the metrics-registry snapshot (admissions, flushes, queue waits,
-pool occupancy, jit-compile counts, XLA backend compiles), and
-``--profile-dir`` captures a ``jax.profiler`` trace whose
-``named_scope``/``TraceAnnotation`` host spans line up with the
-recorder's timeline.
+span timeline — the scheduler's phases (``SCHED_PHASES``: admit,
+admission round, dispatch, decode chunk, drain, idle drain, wait), each
+chunk's counters (steps, active rows, flush steps, flush rows needed and
+computed) on its ``sched.drain`` span, per-request admissions, emits and
+segment flushes — as Chrome-trace JSON for https://ui.perfetto.dev
+(schema-validated on export, gated in CI via
+``python -m repro.serve.telemetry``); ``--metrics`` / ``--metrics-out``
+dump the metrics-registry snapshot (admissions, flush rows and steps,
+queue waits, pool occupancy, device memory, jit-compile counts, XLA
+backend compiles); ``--profile-dir`` captures a ``jax.profiler`` trace,
+in which every recorder span opened around live work has a
+``TraceAnnotation`` twin carrying its arguments. Both files time events
+on the profiler's host clock: with ``--profile-dir`` the ``--trace-out``
+times are µs since that profile's start, the origin of the profile's own
+trace, so merging the two files' events gives one timeline.
 """
 from __future__ import annotations
 
@@ -129,6 +136,7 @@ def main():
     from repro.models import init_params
     from repro.serve import (PrefixCache, Request, RequestError, ServeEngine,
                              SessionStore, Telemetry, validate_chrome_trace)
+    from repro.serve.telemetry import profile_start_ns
     from repro.utils import use_compile_cache
     use_compile_cache()
 
@@ -162,7 +170,10 @@ def main():
     def emit_telemetry():
         """--trace-out / --metrics[-out] epilogue shared by both modes."""
         if args.trace_out:
-            tel.trace.export(args.trace_out)
+            # on the profile's clock and origin when there is a profile
+            origin = (profile_start_ns(args.profile_dir)
+                      if args.profile_dir else None)
+            tel.trace.export(args.trace_out, origin_ns=origin)
             errs = validate_chrome_trace(args.trace_out)
             n = len(tel.trace.spans)
             if errs:

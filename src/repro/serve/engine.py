@@ -202,8 +202,12 @@ class ServeEngine:
             params = jax.device_put(params, pspecs)
         self.params = params
         self._n_layers = StackLayout.from_config(cfg).n_layers
-        self._step = jax.jit(
-            lambda p, s, t: decode_step(p, cfg, s, t, serve_mode=serve_mode))
+        def tail_step(p, s, t):
+            # the admissions' tail pieces (and a session resume's turn)
+            with jax.named_scope("serve.tail_step"):
+                return decode_step(p, cfg, s, t, serve_mode=serve_mode)
+
+        self._step = jax.jit(tail_step)
         self._flush = jax.jit(
             lambda p, s: flush_segment(p, cfg, s)) if cfg.armt else None
         self._loops: Dict = {}    # (max_new, greedy, top_k) -> jitted loop

@@ -169,8 +169,8 @@ class _Slot:
     # host mirror of the device-side in-segment position (DESIGN.md §13):
     # seeded with the admission's pos, advanced one per emitted token, reset
     # at seg_len — exactly the arithmetic decode_step/flush_segment run on
-    # device, so in-graph segment flushes are visible on the trace timeline
-    # without any device readback
+    # device, so in-graph segment flushes are counted and put on the trace
+    # timeline without any device readback (_chunk_counts)
     pos: int = 0
 
 
@@ -596,73 +596,104 @@ class ContinuousScheduler:
                 s.session_id, state=row, pos=int(np.asarray(pos)),
                 pending=np.empty(0, np.int32), tokens=history)
 
-    def _drain_chunk(self, toks, masks) -> Iterator[StreamEvent]:
+    def _chunk_counts(self, masks_np: np.ndarray):
+        """What one chunk computed, from the host copy of its emit masks
+        and the slots' ``pos`` mirrors (advanced here). Returns the
+        counters and the [chunk, n_slots] mask of segment crossings (None
+        without ARMT flushes). The counters: ``steps``;
+        ``slot_steps_active``, the decode rows that emitted a token;
+        ``flush_steps``, the steps whose flush branch ran (some row
+        crossed a segment boundary); ``flush_rows_needed``, the rows that
+        crossed; ``flush_rows_computed``, the rows the masked flush ran,
+        every slot at each flush step. The device's own arithmetic: a row
+        that emits advances ``pos`` by one, and reaching ``seg_len`` flushes
+        it and resets ``pos`` to 0."""
+        counts = {"steps": self.chunk,
+                  "slot_steps_active": int(masks_np.sum())}
+        if not self._armt_flush:
+            return counts, None
+        seg = self.engine.seg_len
+        pos0 = np.array([s.pos for s in self.slots], np.int64)
+        # in-segment position after each step, before any reset
+        after = pos0 + np.cumsum(masks_np, axis=0, dtype=np.int64)
+        cross = masks_np & (after % seg == 0)
+        for b, s in enumerate(self.slots):
+            s.pos = int(after[-1, b] % seg)
+        flush_steps = int(cross.any(axis=1).sum())
+        counts.update(flush_steps=flush_steps,
+                      flush_rows_needed=int(cross.sum()),
+                      flush_rows_computed=self.n_slots * flush_steps)
+        return counts, cross
+
+    def _drain_chunk(self, toks, masks,
+                     queue_depth: int) -> Iterator[StreamEvent]:
         """Cross one chunk's token block to the host and stream its events
         (the single device->host transfer for these ``chunk`` steps).
 
         This is the telemetry piggyback point (DESIGN.md §13): the
         ``decode_chunk`` span brackets exactly the two ``np.asarray``
         transfers that already existed (so its duration is the
-        device-sync + copy wall time), per-request emit stamps and
-        per-chunk occupancy metrics are computed from the host copies, and
-        nothing else touches the device — the one-transfer-per-chunk
-        invariant is regression-tested with telemetry enabled."""
+        device-sync + copy wall time); the ``sched.drain`` span holds the
+        rest, the consumer's handling of every yielded event included, and
+        carries the chunk's counters (``_chunk_counts``) as arguments.
+        Emit stamps, flush instants, counters and gauges come from the
+        host copies, and nothing else touches the device — the
+        one-transfer-per-chunk invariant is regression-tested with
+        telemetry enabled."""
         tel = self.tel
-        n_active = sum(1 for s in self.slots if s.active)
-        with tel.span("decode_chunk", "decode", steps=self.chunk,
-                      active_slots=n_active):
+        with tel.span("decode_chunk", "decode", steps=self.chunk):
             toks_np = np.asarray(toks)
             masks_np = np.asarray(masks)
-        now = time.perf_counter()
-        if tel.trace is not None:
-            for b, s in enumerate(self.slots):
-                if s.active:
-                    n = int(masks_np[:, b].sum())
-                    if n:
-                        tel.emit(s.req_id, now, n)
-        tel.observe("chunk_active_slots", n_active)
-        tel.observe("chunk_admissions_in_flight", len(self._adms))
-        tel.set_gauge("pool_occupancy", self.n_slots - len(self.free))
-        tel.sample_device_memory()
-        for t in range(self.chunk):
-            for b, s in enumerate(self.slots):
-                if not masks_np[t, b] or not s.active:
-                    continue
-                s.remaining -= 1
-                done = s.remaining == 0
-                tok = int(toks_np[t, b])
-                s.tokens.append(tok)
-                if self._armt_flush:
-                    # host pos mirror: the emitted token is the step's input,
-                    # so it advanced pos by one; >= seg_len means the jitted
-                    # chunk flushed this slot's segment at that step
-                    s.pos += 1
-                    if s.pos >= self.engine.seg_len:
-                        s.pos = 0
+        with tel.span("sched.drain", "decode") as drain:
+            now = time.perf_counter()
+            counts, cross = self._chunk_counts(masks_np)
+            drain.set(**counts)
+            if counts.get("flush_steps"):
+                tel.inc("decode_flushes_total", counts["flush_rows_needed"])
+                tel.inc("decode_flush_steps_total", counts["flush_steps"])
+                tel.inc("decode_flush_rows_computed_total",
+                        counts["flush_rows_computed"])
+            if tel.trace is not None:
+                for b, s in enumerate(self.slots):
+                    if s.active:
+                        n = int(masks_np[:, b].sum())
+                        if n:
+                            tel.emit(s.req_id, now, n)
+                if cross is not None:
+                    for b in np.nonzero(cross)[1]:
                         tel.instant("segment_flush", "flush", t=now,
-                                    lane=str(s.req_id))
-                        tel.inc("decode_flushes_total")
-                first = s.t_first is None
-                if first:
-                    s.t_first = now
-                ev = StreamEvent(s.req_id, tok, s.index, done, t_emit=now)
-                if first or done:
-                    ev.queue_wait_s = s.t_admit - s.t_submit
-                    ev.concurrent_admissions = s.n_concurrent
-                if first:
-                    ev.ttft_s = now - s.t_submit
-                if done:
-                    ev.ttft_s = s.t_first - s.t_submit
-                    ev.tok_s = (s.index + 1) / max(now - s.t_admit,
-                                                   1e-9)
-                yield ev
-                s.index += 1
-                if done:
-                    s.active = False
-                    if (s.session_id is not None
-                            and self.engine.session_store is not None):
-                        self._persist_session(b)
-                    self.free.append(b)
+                                    lane=str(self.slots[b].req_id))
+            tel.observe("chunk_queue_depth", queue_depth)
+            tel.set_gauge("pool_occupancy", self.n_slots - len(self.free))
+            for t in range(self.chunk):
+                for b, s in enumerate(self.slots):
+                    if not masks_np[t, b] or not s.active:
+                        continue
+                    s.remaining -= 1
+                    done = s.remaining == 0
+                    tok = int(toks_np[t, b])
+                    s.tokens.append(tok)
+                    first = s.t_first is None
+                    if first:
+                        s.t_first = now
+                    ev = StreamEvent(s.req_id, tok, s.index, done, t_emit=now)
+                    if first or done:
+                        ev.queue_wait_s = s.t_admit - s.t_submit
+                        ev.concurrent_admissions = s.n_concurrent
+                    if first:
+                        ev.ttft_s = now - s.t_submit
+                    if done:
+                        ev.ttft_s = s.t_first - s.t_submit
+                        ev.tok_s = (s.index + 1) / max(now - s.t_admit,
+                                                       1e-9)
+                    yield ev
+                    s.index += 1
+                    if done:
+                        s.active = False
+                        if (s.session_id is not None
+                                and self.engine.session_store is not None):
+                            self._persist_session(b)
+                        self.free.append(b)
 
     def run(self, requests: Iterable[Request]) -> Iterator[
             Union[StreamEvent, RequestError]]:
@@ -699,44 +730,48 @@ class ContinuousScheduler:
 
         queue: deque = deque()           # (request, t_submit-at-pull)
         while True:
+            # one top-level phase span at every instant (SCHED_PHASES)
             # ---- start work: backlog first, then pull from the source ----
-            while self.free and queue and self._can_admit():
-                req, t_sub = queue.popleft()
-                err = self._start(req, t_sub)
-                if err is not None:
-                    yield err
-            while not exhausted:
-                can_start = (bool(self.free) and not queue
-                             and self._can_admit())
-                if not can_start and self.max_queue is None:
-                    # pull model: backpressure by not pulling — nothing is
-                    # read from a live source until we can actually start it
-                    break
-                if (not can_start and self.max_queue is not None
-                        and len(queue) >= self.max_queue + len(self.free)):
-                    # push model at capacity: drain + structured rejection.
-                    # Free slots count as extra queue capacity — a slot left
-                    # idle only because the admission pool is at its
-                    # concurrency cap will serve its queued request as soon
-                    # as a pooled admission lands
-                    req = pull()
-                    if req is None:
-                        break
-                    yield RequestError(
-                        req.req_id, "queue_full",
-                        f"all {self.n_slots} slots busy or spoken for and "
-                        f"queue limit {self.max_queue} reached")
-                    continue
-                req = pull()
-                if req is None:
-                    break
-                t_sub = time.perf_counter()
-                if can_start:
+            with self.tel.span("sched.admit", "admission"):
+                while self.free and queue and self._can_admit():
+                    req, t_sub = queue.popleft()
                     err = self._start(req, t_sub)
                     if err is not None:
                         yield err
-                else:
-                    queue.append((req, t_sub))
+                while not exhausted:
+                    can_start = (bool(self.free) and not queue
+                                 and self._can_admit())
+                    if not can_start and self.max_queue is None:
+                        # pull model: backpressure by not pulling — nothing
+                        # is read from a live source until we can actually
+                        # start it
+                        break
+                    if (not can_start and self.max_queue is not None
+                            and len(queue) >= self.max_queue + len(self.free)):
+                        # push model at capacity: drain + structured
+                        # rejection. Free slots count as extra queue
+                        # capacity — a slot left idle only because the
+                        # admission pool is at its concurrency cap will
+                        # serve its queued request as soon as a pooled
+                        # admission lands
+                        req = pull()
+                        if req is None:
+                            break
+                        yield RequestError(
+                            req.req_id, "queue_full",
+                            f"all {self.n_slots} slots busy or spoken for "
+                            f"and queue limit {self.max_queue} reached")
+                        continue
+                    req = pull()
+                    if req is None:
+                        break
+                    t_sub = time.perf_counter()
+                    if can_start:
+                        err = self._start(req, t_sub)
+                        if err is not None:
+                            yield err
+                    else:
+                        queue.append((req, t_sub))
 
             # ---- one fairness round over the in-flight admissions ----
             toks = masks = None
@@ -745,13 +780,13 @@ class ContinuousScheduler:
 
             # ---- decode chunk (unless the fused launch already ran it) ----
             if toks is None and any(s.active for s in self.slots):
-                (self.pool, self.tok, self.active, self.remaining,
-                 toks, masks) = self._chunk_fn(
-                    self.engine.params, self.pool, self.tok,
-                    self.active, self.remaining)
+                with self.tel.span("sched.dispatch", "decode"):
+                    (self.pool, self.tok, self.active, self.remaining,
+                     toks, masks) = self._chunk_fn(
+                        self.engine.params, self.pool, self.tok,
+                        self.active, self.remaining)
             if toks is not None:
-                self.tel.observe("chunk_queue_depth", len(queue))
-                yield from self._drain_chunk(toks, masks)
+                yield from self._drain_chunk(toks, masks, len(queue))
             elif self._adms:
                 # idle-drain: no decode slot is active, so there is no
                 # chunk to interleave against — drain the pending
@@ -775,9 +810,9 @@ class ContinuousScheduler:
                     # fully idle on a live source that yielded None
                     # ("nothing ready yet"): back off briefly instead of
                     # spinning on next()
-                    time.sleep(1e-3)
+                    with self.tel.span("sched.wait", "idle"):
+                        time.sleep(1e-3)
                 # nothing active, nothing admitting: loop back to pull/admit
-
 
 
 def _chunk_body_factory(cfg, serve_mode: str, seg_len: int, chunk: int):
@@ -790,30 +825,39 @@ def _chunk_body_factory(cfg, serve_mode: str, seg_len: int, chunk: int):
         def body(carry, _):
             state, tok, active, remaining = carry
             emit, emit_mask = tok, active
-            logits, new_state = decode_step(params, cfg, state, tok,
-                                            serve_mode=serve_mode)
+            with jax.named_scope("serve.decode_step"):
+                logits, new_state = decode_step(params, cfg, state, tok,
+                                                serve_mode=serve_mode)
             # freeze EVERY leaf of inactive slots' rows, not just pos:
             # garbage rows never trigger (or mask into) a flush, their SSM
             # carries and cache offsets stop drifting, and — load-bearing
             # for the session store — a row that finished mid-chunk stays
             # bit-exactly at its end-of-generation state until the host
             # extracts it at the chunk boundary
-            new_state = mask_decode_state(active, new_state, state)
+            with jax.named_scope("serve.mask_state"):
+                new_state = mask_decode_state(active, new_state, state)
             if armt_on:
                 boundary = active & (new_state["pos"] >= seg_len)
-                new_state = jax.lax.cond(
-                    boundary.any(),
-                    lambda s: flush_segment(params, cfg, s,
-                                            slot_mask=boundary),
-                    lambda s: s, new_state)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+                def flush(s):
+                    with jax.named_scope("serve.flush_segment"):
+                        return flush_segment(params, cfg, s,
+                                             slot_mask=boundary)
+
+                # the chunk program's only conditional: on a TPU trace,
+                # which carries no scopes, the flush is the operations it
+                # encloses
+                new_state = jax.lax.cond(boundary.any(), flush,
+                                         lambda s: s, new_state)
+            with jax.named_scope("serve.sample"):
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             nxt = jnp.where(active, nxt, tok)
             remaining = remaining - emit_mask.astype(jnp.int32)
             active = active & (remaining > 0)
             return (new_state, nxt, active, remaining), (emit, emit_mask)
 
         # named_scope: XLA profiles label this scan to match the host-side
-        # decode_chunk spans (DESIGN.md §13)
+        # decode_chunk spans, and its step's parts inside it (DESIGN.md §13)
         with jax.named_scope("serve.decode_chunk"):
             (state, tok, active, remaining), (toks, masks) = jax.lax.scan(
                 body, (state, tok, active, remaining), None, length=chunk)

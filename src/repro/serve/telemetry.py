@@ -15,11 +15,12 @@ fallbacks, launch driver, benchmarks):
 
 * :class:`TraceRecorder` — host-clock spans with per-request lanes,
   exportable as Chrome-trace / Perfetto JSON (``chrome://tracing``,
-  https://ui.perfetto.dev). The scheduler emits spans for every decode
-  chunk, admission window, pooled admission round, host-visible segment
-  flush, transplant, session restore, prefix-cache probe, and idle-drain
-  round. The recorder is also the single source of truth for the serving
-  metrics previously re-derived ad hoc in ``benchmarks/bench_serve.py``:
+  https://ui.perfetto.dev) on the JAX profiler's host clock. The
+  scheduler's loop is tiled by the phase spans of :data:`SCHED_PHASES`;
+  it also emits spans for every transplant, host-visible segment flush,
+  session restore and prefix-cache probe. The recorder is also the
+  single source of truth for the serving metrics previously re-derived
+  ad hoc in ``benchmarks/bench_serve.py``:
   :meth:`TraceRecorder.itl_values` / :meth:`TraceRecorder.itl_percentiles`
   (inter-token latencies off the per-chunk emit stamps) and
   :meth:`TraceRecorder.admission_stall_s` (max decode gap overlapping an
@@ -46,8 +47,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-__all__ = ["MetricsRegistry", "TraceRecorder", "Telemetry",
-           "default_registry", "validate_chrome_trace"]
+__all__ = ["MetricsRegistry", "TraceRecorder", "Telemetry", "SCHED_PHASES",
+           "default_registry", "profile_start_ns", "validate_chrome_trace"]
 
 
 # ---------------------------------------------------------------------------
@@ -198,6 +199,22 @@ def default_registry() -> MetricsRegistry:
 SPAN_CATEGORIES = ("decode", "admission", "prefill", "flush", "transplant",
                    "session", "cache", "idle", "generate", "emit")
 
+# The top-level phases of one ``ContinuousScheduler.run`` iteration. At
+# every instant of the loop the scheduler's thread is inside exactly one
+# of them (``admission_round`` also nests inside ``idle_drain_round``):
+#   sched.admit       start work: pull from the source, ``_start``/``_admit``
+#   admission_round   one fairness round over the in-flight admissions
+#   sched.dispatch    launch of the jitted decode chunk
+#   decode_chunk      the chunk's token and mask blocks copied to the host
+#                     (the wait for the device)
+#   sched.drain       the host loop over the chunk's tokens, including the
+#                     consumer's handling of each yielded event
+#   idle_drain_round  admissions drained while no slot decodes
+#   sched.wait        back-off: nothing active, nothing admitting
+SCHED_PHASES = ("sched.admit", "admission_round", "sched.dispatch",
+                "decode_chunk", "sched.drain", "idle_drain_round",
+                "sched.wait")
+
 
 @dataclass
 class _Span:
@@ -213,7 +230,8 @@ class _SpanCtx:
     """Hot-path span context: stamps the host clock and enters a
     ``jax.profiler.TraceAnnotation`` (a ~ns-cost TraceMe — when a profile
     is being captured, the XLA timeline gets a host span lining up with
-    the recorder's: same name, same interval)."""
+    the recorder's: same name, same interval, its arguments as the
+    event's stats)."""
 
     __slots__ = ("rec", "name", "cat", "lane", "args", "t0", "_ann")
 
@@ -223,10 +241,16 @@ class _SpanCtx:
         self.lane, self.args = lane, args
 
     def __enter__(self):
-        self._ann = _profiler().TraceAnnotation(self.name)
+        self._ann = _profiler().TraceAnnotation(self.name, **self.args)
         self.t0 = time.perf_counter()
         self._ann.__enter__()
         return self
+
+    def set(self, **args) -> None:
+        """Arguments known only once the span is open (the chunk counters
+        of ``sched.drain``): added to the span and to its twin's stats."""
+        self.args.update(args)
+        self._ann.set_metadata(**args)
 
     def __exit__(self, *exc):
         self._ann.__exit__(*exc)
@@ -247,6 +271,35 @@ def _profiler():
     return _PROFILER
 
 
+def _clock_pair() -> Tuple[float, int]:
+    """One paired stamp: ``time.perf_counter()`` (the recorder's clock)
+    against the clock the JAX profiler stamps host events with — the
+    system's real-time clock in ns (``time.time_ns``); a profile's planes
+    hold it relative to their ``profile_start_time``."""
+    a = time.perf_counter()
+    ns = time.time_ns()
+    b = time.perf_counter()
+    return (a + b) / 2, ns
+
+
+def profile_start_ns(logdir: str) -> Optional[int]:
+    """``profile_start_time`` of the newest profile under ``logdir`` (a
+    ``jax.profiler.start_trace`` directory): the origin of that profile's
+    timestamps on the profiler's host clock, or None when it has none."""
+    import glob
+    import os
+    found = sorted(glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                             recursive=True), key=os.path.getmtime)
+    if not found:
+        return None
+    from jax.profiler import ProfileData
+    for plane in ProfileData.from_file(found[-1]).planes:
+        for k, v in plane.stats:
+            if k == "profile_start_time":
+                return int(v)
+    return None
+
+
 class TraceRecorder:
     """Host-clock span/instant recorder with per-request lanes.
 
@@ -260,10 +313,15 @@ class TraceRecorder:
     chunk boundary), NOT per token; expansion to per-token stamps happens
     only inside :meth:`itl_values` (every token of a chunk shares the
     chunk-boundary host stamp, by design — chunk-granular latency).
+
+    Stamps are ``time.perf_counter()`` seconds, the client's clock too.
+    The recorder takes one paired stamp against the profiler's host clock
+    at construction, and :meth:`chrome_trace` writes times on that clock.
     """
 
     def __init__(self, t0: Optional[float] = None):
         self.t0 = time.perf_counter() if t0 is None else t0
+        self._pc_ref, self._prof_ref_ns = _clock_pair()
         self.spans: List[_Span] = []
         self.instants: List[_Span] = []
         # req_id -> [(t_emit, n_tokens), ...] per chunk boundary
@@ -334,11 +392,24 @@ class TraceRecorder:
         return stall
 
     # -- export -----------------------------------------------------------
-    def chrome_trace(self) -> Dict[str, Any]:
+    def _profiler_ns(self, t: float) -> int:
+        """A ``perf_counter`` stamp on the profiler's host clock (ns)."""
+        return self._prof_ref_ns + round((t - self._pc_ref) * 1e9)
+
+    def chrome_trace(self, origin_ns: Optional[int] = None) -> Dict[str, Any]:
         """The recorder's timeline as a Chrome-trace JSON object (Perfetto
-        and chrome://tracing both load it). Times are microseconds
-        relative to the recorder's ``t0``; spans are complete ("X")
-        events, instants "i", lanes become named threads of pid 1."""
+        and chrome://tracing both load it). Times are microseconds on the
+        profiler's host clock since ``origin_ns``: by default the
+        recorder's ``t0``; given a profile's start (:func:`profile_start_ns`)
+        they are that profile's own times, so the two timelines overlay
+        (the retroactive ``admission`` spans and the instants included).
+        Spans are complete ("X") events, instants "i", lanes become named
+        threads of pid 1."""
+        if origin_ns is None:
+            origin_ns = self._profiler_ns(self.t0)
+
+        def ts(t: float) -> float:
+            return (self._profiler_ns(t) - origin_ns) * 1e-3
         lanes: Dict[Optional[str], int] = {None: 0}
         events: List[Dict[str, Any]] = [
             {"ph": "M", "pid": 1, "tid": 0, "name": "process_name",
@@ -357,20 +428,21 @@ class TraceRecorder:
 
         for s in self.spans:
             events.append({"ph": "X", "pid": 1, "tid": tid(s.lane),
-                           "name": s.name, "cat": s.cat,
-                           "ts": (s.t0 - self.t0) * 1e6,
+                           "name": s.name, "cat": s.cat, "ts": ts(s.t0),
                            "dur": max((s.t1 - s.t0) * 1e6, 0.0),
                            "args": s.args})
         for s in self.instants:
             events.append({"ph": "i", "pid": 1, "tid": tid(s.lane),
                            "name": s.name, "cat": s.cat, "s": "t",
-                           "ts": (s.t0 - self.t0) * 1e6, "args": s.args})
+                           "ts": ts(s.t0), "args": s.args})
         events.sort(key=lambda e: e.get("ts", -1.0))
-        return {"traceEvents": events, "displayTimeUnit": "ms"}
+        return {"traceEvents": events, "displayTimeUnit": "ms",
+                "otherData": {"clock": "profiler host clock (time.time_ns)",
+                              "origin_ns": origin_ns}}
 
-    def export(self, path: str) -> None:
+    def export(self, path: str, origin_ns: Optional[int] = None) -> None:
         with open(path, "w") as f:
-            json.dump(self.chrome_trace(), f)
+            json.dump(self.chrome_trace(origin_ns), f)
             f.write("\n")
 
 
@@ -432,14 +504,42 @@ def validate_chrome_trace(trace: Any) -> List[str]:
 _MEM_DEVICE: Any = None
 
 
+def _sample_device_memory(reg: MetricsRegistry) -> Dict[str, int]:
+    """Set and return the device-memory gauges (``device_bytes_in_use``,
+    ``device_peak_bytes_in_use``). Sampled at snapshot time as a probe
+    (:class:`Telemetry` registers it), so the serving loop never pays for
+    it. ``Device.memory_stats()`` is a host-side query (no sync), absent
+    on CPU, where this returns {} (the first empty query remembers the
+    backend as statless)."""
+    global _MEM_DEVICE
+    if _MEM_DEVICE is False:
+        return {}
+    if _MEM_DEVICE is None:
+        import jax
+        dev = jax.local_devices()[0]
+        if not dev.memory_stats():
+            _MEM_DEVICE = False
+            return {}
+        _MEM_DEVICE = dev
+    stats = _MEM_DEVICE.memory_stats() or {}
+    out = {f"device_{k}": int(stats[k])
+           for k in ("bytes_in_use", "peak_bytes_in_use") if k in stats}
+    for k, v in out.items():
+        reg.set_gauge(k, v)
+    return out
+
+
 class _NullCtx:
     __slots__ = ()
 
     def __enter__(self):
-        return None
+        return self
 
     def __exit__(self, *exc):
         return False
+
+    def set(self, **args) -> None:
+        pass
 
 
 _NULL = _NullCtx()
@@ -464,6 +564,10 @@ class Telemetry:
                          else (default_registry() if metrics else None))
         self.trace: Optional[TraceRecorder] = (TraceRecorder() if trace
                                                else None)
+        reg = self.registry
+        if reg is not None:
+            reg.register_probe("device_memory",
+                               lambda: _sample_device_memory(reg))
 
     @classmethod
     def disabled(cls) -> "Telemetry":
@@ -505,27 +609,6 @@ class Telemetry:
     def emit(self, req_id, t: float, n_tokens: int) -> None:
         if self.trace is not None:
             self.trace.emit(req_id, t, n_tokens)
-
-    def sample_device_memory(self) -> None:
-        """Chunk-boundary device-memory gauge — ``Device.memory_stats()``
-        is a host-side query (no sync); absent on CPU, so this is a no-op
-        there (the first empty probe remembers the backend as statless,
-        keeping the per-chunk cost to one comparison)."""
-        global _MEM_DEVICE
-        if self.registry is None or _MEM_DEVICE is False:
-            return
-        if _MEM_DEVICE is None:
-            import jax
-            dev = jax.local_devices()[0]
-            if not dev.memory_stats():
-                _MEM_DEVICE = False
-                return
-            _MEM_DEVICE = dev
-        stats = _MEM_DEVICE.memory_stats()
-        if stats:
-            for k in ("bytes_in_use", "peak_bytes_in_use"):
-                if k in stats:
-                    self.registry.set_gauge(f"device_{k}", int(stats[k]))
 
     def snapshot(self) -> Optional[Dict[str, Any]]:
         return self.registry.snapshot() if self.registry is not None else None

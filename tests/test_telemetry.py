@@ -367,9 +367,8 @@ def test_disabled_telemetry_is_noop():
     tel.add_span("a", "decode", 0.0, 1.0)
     tel.instant("b", "flush")
     tel.emit("r", 0.0, 1)
-    with tel.span("c", "decode"):
-        pass
-    tel.sample_device_memory()
+    with tel.span("c", "decode") as sp:
+        sp.set(n=1)
 
 
 # ---------------------------------------------------------------------------
