@@ -3,4 +3,4 @@ from repro.models.model import (init_params, param_specs, init_state,
                                 boundary_logits, embed_segments,
                                 decode_state_init, decode_state_shapes,
                                 decode_state_sharding, decode_step,
-                                flush_segment, mask_decode_state, encode)
+                                flush_segment, encode)

@@ -219,48 +219,55 @@ def kv_cache_init(batch: int, max_len: int, cfg, dtype) -> Dict:
     }
 
 
-def decode_attention(x, p, cfg, cache: Dict, pos: jax.Array):
-    """Decode step for Tq >= 1 queries (Tq=1: autoregressive decode; Tq>1:
-    chunked prefill / ARMT memory-token flush). x: [B,Tq,D]; pos: scalar
-    int32 = number of tokens already in the cache, or int32 [B] vector of
-    per-row positions (continuous-batching slots at heterogeneous phases).
-    Returns (out, new_cache)."""
-    B, Tq, _ = x.shape
+def _decode_positions(pos, Tq: int) -> jax.Array:
+    """Positions of Tq decode rows starting at ``pos``: [B, Tq] for a
+    per-row int32 [B] ``pos``, [1, Tq] for a scalar."""
+    if getattr(pos, "ndim", 0) == 1:
+        return pos[:, None] + jnp.arange(Tq)[None, :]
+    return (pos + jnp.arange(Tq))[None]
+
+
+def decode_qkv(x, p, cfg, pos):
+    """q, k, v of Tq >= 1 decode rows (Tq=1: autoregressive decode; Tq>1:
+    chunked prefill / ARMT memory-token flush), rotated to their positions.
+    x: [B,Tq,D]; pos: scalar int32 = number of tokens already in the cache,
+    or int32 [B] vector of per-row positions (continuous-batching slots at
+    heterogeneous phases). The caller writes k, v into its cache at pos
+    and attends with ``decode_attend``."""
     q, k, v = _project_qkv(x, p, cfg)
-    per_slot = getattr(pos, "ndim", 0) == 1
-    if per_slot:
-        positions = pos[:, None] + jnp.arange(Tq)[None, :]         # [B,Tq]
-        q, k = rope_qk(q, k, cfg, positions)
-        upd = jax.vmap(lambda c, u, s: jax.lax.dynamic_update_slice_in_dim(
-            c, u, s, axis=0))
-        ck, cv = upd(cache["k"], k, pos), upd(cache["v"], v, pos)
-        qpos = positions[:, :, None]                               # [B,Tq,1]
-        kpos = jnp.arange(ck.shape[1])[None, None, :]              # [1,1,S]
-        mask = kpos <= qpos
-        if cfg.sliding_window > 0:
-            mask &= kpos > (qpos - cfg.sliding_window)
-        mask = mask[:, None]                                       # [B,1,Tq,S]
-    else:
-        q, k = rope_qk(q, k, cfg, (pos + jnp.arange(Tq))[None])
-        ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, pos, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, pos, axis=1)
-        S = ck.shape[1]
-        kpos = jnp.arange(S)[None, :]                              # [1,S]
-        qpos = (pos + jnp.arange(Tq))[:, None]                     # [Tq,1]
-        mask = kpos <= qpos
-        if cfg.sliding_window > 0:
-            mask &= kpos > (qpos - cfg.sliding_window)
-        mask = mask[None, None]
+    q, k = rope_qk(q, k, cfg, _decode_positions(pos, x.shape[1]))
+    return q, k, v
+
+
+def decode_attend(q, ck, cv, p, cfg, pos):
+    """Tq queries at ``pos`` against a cache [B,S,Hkv,hd] that already
+    holds their own rows; causal (and windowed) per row. -> [B,Tq,D]."""
+    B, Tq = q.shape[:2]
+    qpos = _decode_positions(pos, Tq)[:, :, None]                 # [.,Tq,1]
+    kpos = jnp.arange(ck.shape[1])[None, None, :]                  # [1,1,S]
+    mask = kpos <= qpos
+    if cfg.sliding_window > 0:
+        mask &= kpos > (qpos - cfg.sliding_window)
     if getattr(cfg, "attn_impl", "dense") == "pallas" and Tq == 1:
         # single-token serve hot path: the dedicated decode kernel
         # (kernels/decode_attention.py) reads only the valid cache prefix
         from repro.kernels import ops as kops
-        lens = (pos if per_slot else jnp.full((B,), pos, jnp.int32)) + 1
+        lens = jnp.broadcast_to(pos, (B,)).astype(jnp.int32) + 1
         o = kops.decode_attention(
             q[:, 0], ck, cv, lens, window=cfg.sliding_window,
             use_kernel=True,
             interpret=cfg.kernel_backend == "pallas_interpret")[:, None]
     else:
-        o = sdpa(q, ck, cv, mask)
+        # sdpa's math with GQA as a grouped contraction: query head h reads
+        # KV head h // g straight from the cache, which is never repeated
+        # to n_heads (that would write and read it g times over a step)
+        Hkv, hd = ck.shape[2], ck.shape[3]
+        g = cfg.n_heads // Hkv
+        logits = jnp.einsum("btkgd,bskd->bkgts",
+                            q.reshape(B, Tq, Hkv, g, hd), ck)
+        logits = jnp.where(mask[:, None, None],
+                           logits.astype(jnp.float32) * hd ** -0.5, NEG_INF)
+        w = jax.nn.softmax(logits, axis=-1).astype(cv.dtype)
+        o = jnp.einsum("bkgts,bskd->btkgd", w, cv)
     o = o.reshape(B, Tq, cfg.n_heads * cfg.head_dim)
-    return jnp.einsum("bte,ed->btd", o, p["wo"]), {"k": ck, "v": cv}
+    return jnp.einsum("bte,ed->btd", o, p["wo"])

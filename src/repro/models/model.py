@@ -8,8 +8,9 @@ plus a serving path (`decode_step`) that runs one token against carried state:
 'cache' mode (full KV cache — standard decoding) or 'armt' mode (associative
 memory + current-segment cache — constant memory in sequence length).
 
-Decode reuses the sequential executor over a single-token "segment", so the
-per-layer code is shared and the HLO stays scan-compact for 61-72-layer archs.
+Decode has its own layer loop (`_decode_layers`): a scan over the stacked
+layer params that updates the pooled state in place, so the HLO stays
+scan-compact for 61-72-layer archs and a step writes only what it changes.
 """
 from __future__ import annotations
 
@@ -23,8 +24,8 @@ from repro.core.memory import mem_read, mem_update
 from repro.core.schedule import StackLayout
 from repro.core.sequential import run_sequential
 from repro.core.diagonal import boundary_states_from_capture, run_diagonal
-from repro.models.attention import (attention, cross_kv, decode_attention,
-                                    sdpa, causal_mask)
+from repro.models.attention import (cross_attention, cross_kv, decode_attend,
+                                    decode_qkv)
 from repro.models.blocks import (block_param_init, block_state_init,
                                  make_apply_block, _is_attn)
 from repro.models.layers import ffn, norm, norm_init
@@ -385,34 +386,6 @@ def decode_state_init(cfg: ArchConfig, batch: int, *, serve_mode: str,
     return state
 
 
-def mask_decode_state(mask: jax.Array, new_state: Dict, old_state: Dict) -> Dict:
-    """Per-row merge of two decode states: rows where ``mask`` is True take
-    ``new_state``, others keep ``old_state``. mask: bool [B].
-
-    Handles the three leaf layouts of a decode state: prelude leaves
-    [B, ...], pattern leaves [n_super, B, ...], and ``pos`` ([B] or scalar —
-    a scalar pos is merged only if the whole mask agrees, which per-slot
-    callers never rely on; they use per_slot_pos states)."""
-    def sel(axis):
-        def one(n, o):
-            shape = [1] * n.ndim
-            shape[axis] = mask.shape[0]
-            return jnp.where(mask.reshape(shape), n, o)
-        return one
-
-    out = {
-        "prelude": jax.tree_util.tree_map(sel(0), tuple(new_state["prelude"]),
-                                          tuple(old_state["prelude"])),
-        "pattern": jax.tree_util.tree_map(sel(1), tuple(new_state["pattern"]),
-                                          tuple(old_state["pattern"])),
-    }
-    if "pos" in new_state:
-        np_, op = new_state["pos"], old_state["pos"]
-        out["pos"] = jnp.where(mask, np_, op) if np_.ndim else jnp.where(
-            mask.all(), np_, op)
-    return out
-
-
 def _pos_embed_slice(table: jax.Array, pos: jax.Array, T: int) -> jax.Array:
     """Slice T rows of a learned position table starting at ``pos`` (scalar)
     or per-row at ``pos[b]`` (vector) -> [1 or B, T, D]."""
@@ -422,8 +395,123 @@ def _pos_embed_slice(table: jax.Array, pos: jax.Array, T: int) -> jax.Array:
     return jax.lax.dynamic_slice_in_dim(table, pos, T, axis=0)[None]
 
 
+def _write_rows(leaf: jax.Array, i, rows: jax.Array, pos: jax.Array,
+               keep: Optional[jax.Array]) -> jax.Array:
+    """Write ``rows`` [B, Tq, ...] at in-segment ``pos`` (scalar, or int32
+    [B] per row) into layer ``i`` of a stacked cache leaf [n_super, B, S,
+    ...] (``i`` None: a prelude leaf [B, S, ...]), in place; needs pos +
+    Tq <= S. Rows where ``keep`` is False are written their current values
+    back, so they stay bit-exactly as they were.
+
+    The write rewrites the whole 128-position block around the Tq
+    positions (one in-place ``dynamic_update_slice`` per row, or one for
+    all rows at a scalar pos), the other positions with their own values.
+    On a TPU the cache lives with its position axis minor, which is how
+    decode attention reads it; whole 128-lane blocks keep it there, where
+    Tq bare rows make XLA relayout the whole cache around the loop."""
+    B, Tq = rows.shape[:2]
+    S = leaf.shape[-(rows.ndim - 1)]
+    W = min(S, -(-(Tq + 127) // 128) * 128)
+    lead = () if i is None else (i,)
+    rest, tail = rows.shape[2:], (1,) * (rows.ndim - 2)
+    zero = jnp.zeros((B, W) + rest, rows.dtype)
+    groups = ([(0, B, pos)] if pos.ndim == 0
+              else [(b, 1, pos[b]) for b in range(B)])
+    for b, nb, p0 in groups:
+        start = jnp.clip(p0 // 128 * 128, 0, S - W)
+        at = lead + (b, start) + (0,) * len(tail)
+        shape = (1,) * len(lead) + (nb, W) + rest
+        # the rows shifted to their offset in the block: a slice of
+        # [W zeros, rows, W - Tq zeros] (no gather, no layout of its own)
+        src = jnp.concatenate([zero[:nb], rows[b:b + nb],
+                               zero[:nb, :W - Tq]], axis=1)
+        new = jax.lax.dynamic_slice_in_dim(src, W - (p0 - start), W, axis=1)
+        p = start + jnp.arange(W)
+        put = ((p >= p0) & (p < p0 + Tq))[None]                 # [1, W]
+        if keep is not None:
+            put = put & keep[b:b + nb, None]
+        blk = jnp.where(put.reshape(put.shape + tail), new.reshape(shape),
+                        jax.lax.dynamic_slice(leaf, at, shape))
+        leaf = jax.lax.dynamic_update_slice(leaf, blk, at)
+    return leaf
+
+
+def _write_slice(leaf: jax.Array, i, new: jax.Array,
+                 keep: Optional[jax.Array]) -> jax.Array:
+    """Write a block's rewritten state ``new`` [B, ...] as layer ``i`` of a
+    stacked leaf (``i`` None: the whole prelude leaf); rows where ``keep``
+    is False keep their current values."""
+    old = leaf if i is None else jax.lax.dynamic_index_in_dim(
+        leaf, i, 0, keepdims=False)
+    if keep is not None:
+        new = jnp.where(keep.reshape((-1,) + (1,) * (new.ndim - 1)), new, old)
+    if i is None:
+        return new
+    return jax.lax.dynamic_update_index_in_dim(leaf, new, i, 0)
+
+
+def _decode_layers(layout: StackLayout, params: Dict, state: Dict,
+                   x: jax.Array, apply, pos: jax.Array,
+                   keep: Optional[jax.Array]):
+    """The decode layer loop: x [B, Tq, D] through every layer against the
+    carried state, which stays stacked and is updated in place. A
+    ``lax.scan`` over the stacked pattern params carries (x, the stacked
+    pattern state, the layer index); each block reads its layer's leaves
+    and writes only what it changes:
+
+      * rows: ``put_rows({"k": k, "v": v})`` writes its Tq new K/V rows at
+        each row's ``pos`` and returns the layer's cache holding them;
+      * whole leaves: what the block returns in ``new`` (Mamba ``h`` /
+        ``conv``; the flush's ``A``, ``z`` and reset caches) is written as
+        the layer's slice; a leaf it does not return is not written.
+
+    ``keep``: bool [B] rows to write (None: every row); the other rows
+    keep their state bit for bit. Prefill's recurrence rewrites the whole
+    state every segment and stays on ``run_sequential``; this loop is
+    decode's alone. Returns (y, {'prelude', 'pattern'})."""
+
+    def layer(t, p, x, leaves, i):
+        leaves = dict(leaves)
+
+        def read(name):
+            a = leaves[name]
+            return a if i is None else jax.lax.dynamic_index_in_dim(
+                a, i, 0, keepdims=False)
+
+        def put_rows(rows):
+            for name, r in rows.items():
+                leaves[name] = _write_rows(leaves[name], i, r, pos, keep)
+            return {name: read(name) for name in rows}
+
+        y, new = apply(t, p, x, {name: read(name) for name in leaves},
+                       put_rows)
+        for name, v in new.items():
+            leaves[name] = _write_slice(leaves[name], i, v, keep)
+        return y, leaves
+
+    prelude = []
+    for j, t in enumerate(layout.prelude):
+        x, st = layer(t, params["prelude"][j], x, state["prelude"][j], None)
+        prelude.append(st)
+
+    def superblock(carry, sb_params):
+        x, pattern, i = carry
+        pattern = list(pattern)
+        for p_i, t in enumerate(layout.pattern):
+            x, pattern[p_i] = layer(t, sb_params[p_i], x, pattern[p_i], i)
+        return (x, tuple(pattern), i + 1), None
+
+    pattern = tuple(state["pattern"])
+    if layout.pattern:
+        (x, pattern, _), _ = jax.lax.scan(
+            superblock, (x, pattern, jnp.int32(0)), params["pattern"])
+    return x, {"prelude": tuple(prelude), "pattern": pattern}
+
+
 def make_decode_apply(cfg: ArchConfig, serve_mode: str, pos):
-    """Block apply for decode: x [B, Tq, D] against per-layer caches."""
+    """Block apply for ``_decode_layers``: (t, p, x [B, Tq, D], st,
+    put_rows) -> (y, new) — ``st`` the layer's leaves, ``new`` the leaves
+    the block rewrote whole (attention blocks write only K/V rows)."""
     armt_on = serve_mode == "armt" and cfg.armt is not None
 
     def apply_ffn(t, h, p):
@@ -434,41 +522,42 @@ def make_decode_apply(cfg: ArchConfig, serve_mode: str, pos):
             return h + ffn(cfg.act, norm(cfg.norm, h, p["ln2"]), p["ffn"])
         return h
 
-    def apply(t, p, x, st):
-        new = dict(st)
+    def apply(t, p, x, st, put_rows):
         if _is_attn(t):
             if armt_on:
                 x = x + mem_read(p["mem"], st, x, cfg.armt)
-            a, kvc = decode_attention(norm(cfg.norm, x, p["ln1"]), p["attn"],
-                                      cfg, {"k": st["k"], "v": st["v"]}, pos)
-            new["k"], new["v"] = kvc["k"], kvc["v"]
-            h = x + a
+            q, k, v = decode_qkv(norm(cfg.norm, x, p["ln1"]), p["attn"], cfg,
+                                 pos)
+            cache = put_rows({"k": k, "v": v})
+            h = x + decode_attend(q, cache["k"], cache["v"], p["attn"], cfg,
+                                  pos)
             if t == "dec":
-                from repro.models.attention import cross_attention
                 h = h + cross_attention(norm(cfg.norm, h, p["ln_x"]),
                                         p["xattn"], st["ck"], st["cv"], cfg)
-            y = apply_ffn(t, h, p)
-            return y, new
+            return apply_ffn(t, h, p), {}
         if t.startswith("mamba"):
             mix, new_ssm = mamba_mixer(norm(cfg.norm, x, p["ln1"]), p["mixer"],
                                        cfg.ssm,
                                        {"h": st["h"], "conv": st["conv"]})
-            y = apply_ffn(t, x + mix, p)
-            new.update(new_ssm)
-            return y, new
+            return apply_ffn(t, x + mix, p), new_ssm
         raise ValueError(t)
 
     return apply
 
 
 def decode_step(params: Dict, cfg: ArchConfig, state: Dict,
-                tokens: jax.Array, *, serve_mode: str = "armt"):
+                tokens: jax.Array, *, serve_mode: str = "armt",
+                active: Optional[jax.Array] = None):
     """Decoding step. tokens: [B] (one step) or [B, Tq] (chunked prefill) ->
     (logits of the last position [B, V] fp32, new state).
 
-    Runs the layer stack via the sequential executor over a single
-    "segment" so the lowered HLO is a compact scan for deep archs.
-    """
+    The state is updated in place (``_decode_layers``): each layer writes
+    its Tq new K/V rows, and nothing else changes in decode. active:
+    optional bool [B] slot mask (needs a per-slot ``pos``) — rows where it
+    is False keep every leaf and ``pos`` bit for bit; their logits are
+    computed but meaningless."""
+    assert active is None or state["pos"].ndim == 1, (
+        "decode_step(active=...) needs a per-slot pos vector")
     layout = StackLayout.from_config(cfg)
     pos = state["pos"]
     toks = tokens if tokens.ndim == 2 else tokens[:, None]
@@ -477,14 +566,11 @@ def decode_step(params: Dict, cfg: ArchConfig, state: Dict,
     if "pos_embed" in params:
         x = x + _pos_embed_slice(params["pos_embed"], pos, Tq).astype(x.dtype)
     apply = make_decode_apply(cfg, serve_mode, pos)
-    exec_params = {"prelude": params["prelude"], "pattern": params["pattern"]}
-    exec_state = {"prelude": state["prelude"], "pattern": state["pattern"]}
-    ys, fin = run_sequential(layout, exec_params, exec_state, x[None], apply)
-    h = norm(cfg.norm, ys[0, :, -1], params["final_norm"])
+    y, layers = _decode_layers(layout, params, state, x, apply, pos, active)
+    h = norm(cfg.norm, y[:, -1], params["final_norm"])
     logits = _head_matmul(params, cfg, h).astype(jnp.float32)
-    new_state = {"prelude": fin["prelude"], "pattern": fin["pattern"],
-                 "pos": pos + Tq}
-    return logits, new_state
+    new_pos = pos + Tq if active is None else jnp.where(active, pos + Tq, pos)
+    return logits, {**layers, "pos": new_pos}
 
 
 def flush_segment(params: Dict, cfg: ArchConfig, state: Dict,
@@ -495,7 +581,8 @@ def flush_segment(params: Dict, cfg: ArchConfig, state: Dict,
 
     slot_mask: optional bool [B] — flush only those batch rows (decode
     slots), keeping the other rows' state/cache/pos untouched. The flush is
-    computed for every row and merged with ``jnp.where`` so heterogeneous
+    computed for every row and written through the same in-place layer loop
+    as ``decode_step``, masked per row at each write, so heterogeneous
     slots hitting segment boundaries at different steps stay inside one
     jitted step (no host branching); requires a per-slot ``pos`` vector."""
     assert cfg.armt is not None
@@ -517,22 +604,17 @@ def flush_segment(params: Dict, cfg: ArchConfig, state: Dict,
     pos = state["pos"]
     base_apply = make_decode_apply(cfg, "armt", pos)
 
-    def apply(t, p, xx, st):
-        y, new = base_apply(t, p, xx, st)
+    def apply(t, p, xx, st, put_rows):
+        y, new = base_apply(t, p, xx, st, put_rows)
         if _is_attn(t) and t != "enc" and "A" in st:
             upd = mem_update(p["mem"], {"A": st["A"], "z": st["z"]}, y, cfg.armt)
-            new = dict(new)
-            new.update(upd)
-            # reset current-segment cache
-            new["k"] = jnp.zeros_like(st["k"])
-            new["v"] = jnp.zeros_like(st["v"])
+            # and reset the current-segment cache
+            new = {**new, **upd, "k": jnp.zeros_like(st["k"]),
+                   "v": jnp.zeros_like(st["v"])}
         return y, new
 
-    exec_params = {"prelude": params["prelude"], "pattern": params["pattern"]}
-    exec_state = {"prelude": state["prelude"], "pattern": state["pattern"]}
-    _, fin = run_sequential(layout, exec_params, exec_state, x[None], apply)
-    flushed = {"prelude": fin["prelude"], "pattern": fin["pattern"],
-               "pos": jnp.zeros_like(state["pos"])}
-    if slot_mask is None:
-        return flushed
-    return mask_decode_state(slot_mask, flushed, state)
+    _, layers = _decode_layers(layout, params, state, x, apply, pos,
+                               slot_mask)
+    new_pos = (jnp.zeros_like(pos) if slot_mask is None
+               else jnp.where(slot_mask, 0, pos))
+    return {**layers, "pos": new_pos}

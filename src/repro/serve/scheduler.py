@@ -8,9 +8,10 @@ every layer, the current-segment KV cache, and an *in-segment position* —
 requests sit at heterogeneous segment phases inside one jitted step.
 
 The decode loop is a packed ``decode_step`` over all slots followed by a
-``jnp.where``-masked ``flush_segment`` for exactly the slots that crossed a
-segment boundary this step — one compiled graph, no host branching, no
-per-token device->host transfer. Tokens cross to the host once per
+masked ``flush_segment`` for exactly the slots that crossed a segment
+boundary this step — one compiled graph, no host branching, no per-token
+device->host transfer. Both write the pooled state in place, masked per
+row at each write (models/model.py ``_decode_layers``). Tokens cross to the host once per
 ``chunk`` steps (a single transfer of the chunk's token block), which is
 when finished slots are freed and queued requests admitted.
 
@@ -75,10 +76,11 @@ Slot-state invariants (DESIGN.md §8):
     inactive slot's row is garbage and is fully overwritten at admission
     (every leaf row, pos, and pending token) — nothing is read from it;
   * inactive slots still flow through the packed step (fixed shapes), but
-    every leaf of their state is frozen by a ``jnp.where`` row-merge
-    (mask_decode_state) and the flush mask excludes them — so a finished
-    request's row is bit-exactly its end-of-generation state at the chunk
-    boundary, which is what the session store persists (§9);
+    every leaf of their state is frozen at the write (``decode_step``'s
+    ``active`` mask: their K/V rows are written their own values back,
+    whole leaves and pos keep theirs) and the flush mask excludes them — so
+    a finished request's row is bit-exactly its end-of-generation state at
+    the chunk boundary, which is what the session store persists (§9);
   * per-slot independence of the math itself: all decode ops are
     batch-row-local. The one exception is MoE with `dispatch='global'` and
     a tight capacity factor (capacity drops depend on co-batched rows) —
@@ -98,7 +100,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models import decode_step, flush_segment, mask_decode_state
+from repro.models import decode_step, flush_segment
 
 
 @dataclass
@@ -825,17 +827,16 @@ def _chunk_body_factory(cfg, serve_mode: str, seg_len: int, chunk: int):
         def body(carry, _):
             state, tok, active, remaining = carry
             emit, emit_mask = tok, active
+            # inactive rows are masked at every write: their state (every
+            # leaf and pos) stays bit for bit, so garbage rows never trigger
+            # (or mask into) a flush, and — load-bearing for the session
+            # store — a row that finished mid-chunk stays at its
+            # end-of-generation state until the host extracts it at the
+            # chunk boundary
             with jax.named_scope("serve.decode_step"):
                 logits, new_state = decode_step(params, cfg, state, tok,
-                                                serve_mode=serve_mode)
-            # freeze EVERY leaf of inactive slots' rows, not just pos:
-            # garbage rows never trigger (or mask into) a flush, their SSM
-            # carries and cache offsets stop drifting, and — load-bearing
-            # for the session store — a row that finished mid-chunk stays
-            # bit-exactly at its end-of-generation state until the host
-            # extracts it at the chunk boundary
-            with jax.named_scope("serve.mask_state"):
-                new_state = mask_decode_state(active, new_state, state)
+                                                serve_mode=serve_mode,
+                                                active=active)
             if armt_on:
                 boundary = active & (new_state["pos"] >= seg_len)
 

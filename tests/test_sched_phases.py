@@ -220,8 +220,8 @@ def test_chunk_program_unchanged_by_scopes(monkeypatch):
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
     bare_text, bare = _hlo(cfg, params)
-    for scope in ("serve.decode_step", "serve.mask_state",
-                  "serve.flush_segment", "serve.sample"):
+    for scope in ("serve.decode_step", "serve.flush_segment",
+                  "serve.sample"):
         assert scope in scoped_text and scope not in bare_text, scope
     assert " conditional(" in scoped
     assert scoped == bare

@@ -10,6 +10,7 @@ The topology is described inside a module fixture, never at import: only
 one process may load the TPU compiler library at a time, and every test
 worker imports this file. Keep these tests in this one file."""
 import dataclasses
+import re
 
 import pytest
 
@@ -161,3 +162,120 @@ def test_serving_program_fits_v5e(one_chip, program):
     used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert used <= HBM_BYTES, (program, used)
+
+
+# -- the scheduler's decode chunk as `danube.chat` runs it: the
+# h2o-danube-1.8b-armt configuration of the chip benchmark, 16 slots,
+# chunk 8, the pool donated
+
+DANUBE_SLOTS, DANUBE_CHUNK = 16, 8
+# compiled temp bytes of that chunk program: 0.79 GB with the in-place
+# decode writes, 6.56 GB with the whole-state select of every step
+# (PERF.md §5)
+DANUBE_CHUNK_TEMP_BYTES = 1_250_000_000
+
+
+@pytest.fixture(scope="module")
+def danube_chunk(one_chip):
+    """(HLO text, memory analysis, pool leaf shapes) of the compiled chunk
+    program, compiled once for every test that reads it."""
+    import json
+    import sys
+    from pathlib import Path
+    from repro.models import decode_state_init, init_params
+    from repro.serve.scheduler import _chunk_body_factory
+
+    chip = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+    sys.path.insert(0, str(chip))
+    import run
+    conf = json.loads((chip / "configs" / "h2o-danube-1.8b-armt.json")
+                      .read_text())
+    cfg = run.build_cfg(conf)
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = place(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    pool = place(jax.eval_shape(lambda: decode_state_init(
+        cfg, DANUBE_SLOTS, serve_mode="armt", max_len=4096,
+        dtype=jnp.bfloat16, per_slot_pos=True)))
+    vec = [jax.ShapeDtypeStruct((DANUBE_SLOTS,), dt, sharding=one_chip)
+           for dt in (jnp.int32, jnp.bool_, jnp.int32)]
+    fn = jax.jit(_chunk_body_factory(cfg, "armt", cfg.armt.segment_len,
+                                     DANUBE_CHUNK),
+                 donate_argnums=(1, 2, 3, 4))
+    compiled = fn.lower(params, pool, *vec).compile()
+    shapes = {tuple(a.shape) for a in jax.tree_util.tree_leaves(
+        {"prelude": pool["prelude"], "pattern": pool["pattern"]})}
+    return compiled.as_text(), compiled.memory_analysis(), shapes
+
+
+def _hlo_computations(text):
+    """{computation name: its instruction lines} of an HLO module's text."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head and not line.startswith(" "):
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return comps
+
+
+_CALLEE = re.compile(r"(?:body|condition|calls|to_apply|true_computation|"
+                     r"false_computation)=%([\w.\-]+)")
+
+
+def _callees(line):
+    names = _CALLEE.findall(line)
+    branches = re.search(r"branch_computations=\{([^}]*)\}", line)
+    if branches:
+        names += [b.strip().lstrip("%") for b in branches.group(1).split(",")]
+    return names
+
+
+def _closure(comps, roots, skip=frozenset()):
+    seen, todo = set(), list(roots)
+    while todo:
+        c = todo.pop()
+        if c in seen or c in skip or c not in comps:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            if " conditional(" not in line:
+                todo += _callees(line)
+    return seen
+
+
+def test_decode_chunk_writes_no_whole_pool_leaf(danube_chunk):
+    """Each decode step writes the rows it changes in place: inside the
+    program's loops, apart from the flush the conditional encloses, no
+    select, broadcast or copy has the shape of a whole pool leaf."""
+    text, _, shapes = danube_chunk
+    assert text.startswith("HloModule jit_chunk_fn")
+    assert text.count(" conditional(") == 1
+    comps = _hlo_computations(text)
+    flush = _closure(comps, [c for lines in comps.values() for line in lines
+                             if " conditional(" in line
+                             for c in _callees(line)])
+    bodies = [b for c, lines in comps.items() if c not in flush
+              for line in lines if " while(" in line
+              for b in re.findall(r"body=%([\w.\-]+)", line)]
+    assert bodies
+    op = re.compile(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* "
+                    r"(select|broadcast|copy|copy-start)\(")
+    whole = [(c, line.strip()[:160]) for c in _closure(comps, bodies, flush)
+             for line in comps[c] if (m := op.match(line))
+             and tuple(int(d) for d in m.group(1).split(",") if d) in shapes]
+    assert not whole, whole
+
+
+def test_decode_chunk_temp_bytes(danube_chunk):
+    _, ma, _ = danube_chunk
+    assert ma.temp_size_in_bytes <= DANUBE_CHUNK_TEMP_BYTES, \
+        ma.temp_size_in_bytes
